@@ -60,6 +60,8 @@ def _apply_round(states, w: int, r: int) -> None:
 
 def worker_main(root: str, wid: str, kill_at: int | None,
                 counter_file: str | None, go_file: str | None) -> None:
+    from repro.jaxenv import pin_cpu
+    pin_cpu()
     from repro.core import faults as F, maps as M, shm as SH
 
     if kill_at is not None:
@@ -287,6 +289,10 @@ def _cache_drill(root: str) -> int:
 
 
 def main() -> int:
+    # the drill fleet is host work: neither this process nor its workers
+    # may take a chip (each spawned worker pins itself as well)
+    from repro.jaxenv import pin_cpu
+    pin_cpu()
     root = tempfile.mkdtemp(prefix="bpftime_chaos_")
     try:
         return _run(root)

@@ -51,6 +51,8 @@ HIST_RMS = """
 def worker_main(root: str, wid: str) -> None:
     """One trainer-analogue process: probe compiled into its step, shm
     joined as workers/<wid>/, one publish per step."""
+    from repro.jaxenv import pin_cpu
+    pin_cpu()
     import jax
     import jax.numpy as jnp
     from repro.core import events as E, jit as J, maps as M
@@ -102,6 +104,8 @@ def tree_worker_main(root: str, wid: str) -> None:
     """Lightweight shm-only worker for the tree act: publishes LOG2HIST
     deltas straight through the map plane (no jax runtime — the tree
     demo is about the aggregation topology, not program execution)."""
+    from repro.jaxenv import pin_cpu
+    pin_cpu()
     from repro.core import maps as M, shm as SH
 
     specs = [M.MapSpec("tree_hist", M.MapKind.LOG2HIST)]
@@ -155,6 +159,10 @@ def _run_tree_fleet(root: str, tree: bool) -> np.ndarray:
 
 
 def main() -> int:
+    # the demo fleet is host work: neither this process nor its workers
+    # may take a chip (each spawned worker pins itself as well)
+    from repro.jaxenv import pin_cpu
+    pin_cpu()
     root = tempfile.mkdtemp(prefix="bpftime_fleet_")
     try:
         return _run(root)

@@ -1789,4 +1789,7 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    # aggregation is host work: on a chip host the chip stays the trainer's
+    from repro.jaxenv import pin_cpu
+    pin_cpu()
     sys.exit(main())

@@ -29,6 +29,8 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ops
+
 EVENT_WIDTH = 16
 KIND_ENTRY = 0    # uprobe
 KIND_EXIT = 1     # uretprobe
@@ -39,44 +41,6 @@ FX_ONE = 1 << FX_SHIFT
 _FX_MAX = (1 << 62) - 1
 
 I64 = jnp.int64
-
-
-def _fallback_tensor_stats(x) -> dict:
-    """Self-contained jnp twin of repro.kernels.ref.tensor_stats — the
-    EXPLICIT fallback used when the Pallas kernels package is unavailable
-    (optional layer). Semantics must match the kernel exactly; the
-    differential test in tests/test_kernels_fallback.py pins it."""
-    xf = jnp.asarray(x, jnp.float32).reshape(-1)
-    nan = jnp.isnan(xf)
-    inf = jnp.isinf(xf)
-    bad = nan | inf
-    n_ok = jnp.maximum(jnp.sum(~bad).astype(jnp.float32), 1.0)
-    z = jnp.where(bad, 0.0, xf)
-    mn = jnp.min(jnp.where(bad, jnp.inf, xf))
-    mx = jnp.max(jnp.where(bad, -jnp.inf, xf))
-    any_ok = jnp.any(~bad)
-    mn = jnp.where(any_ok, mn, 0.0)
-    mx = jnp.where(any_ok, mx, 0.0)
-    return {
-        "mean": jnp.sum(z) / n_ok,
-        "rms": jnp.sqrt(jnp.sum(z * z) / n_ok),
-        "min": mn,
-        "max": mx,
-        "absmax": jnp.maximum(jnp.abs(mn), jnp.abs(mx)),
-        "nan_cnt": jnp.sum(nan).astype(I64),
-        "inf_cnt": jnp.sum(inf).astype(I64),
-    }
-
-
-def default_tensor_stats(tensor) -> dict:
-    """The collector's stats path: the fused kernels package when
-    importable, else the in-module jnp fallback — probes keep working on
-    hosts without the accelerator toolchain."""
-    try:
-        from repro.kernels import ops
-    except ImportError:
-        return _fallback_tensor_stats(tensor)
-    return ops.tensor_stats(tensor)
 
 
 def to_fx(x):
@@ -186,7 +150,9 @@ class Collector:
         self.frames[-1].rows.append(rows)
 
     def emit_tensor_event(self, site_id: int, kind: int, tensor):
-        st = self._stats(tensor)
+        # stats only observe the tensor; without the stop the enclosing
+        # value_and_grad would try to differentiate the Pallas kernel
+        st = self._stats(jax.lax.stop_gradient(tensor))
         row = jnp.stack([
             jnp.asarray(site_id, I64),
             jnp.asarray(kind, I64),
@@ -204,7 +170,7 @@ class Collector:
     def _stats(self, tensor):
         if self.stats_fn is not None:
             return self.stats_fn(tensor)
-        return default_tensor_stats(tensor)
+        return ops.tensor_stats(tensor)
 
     def stacked_rows(self, frame: _Frame):
         parts = []

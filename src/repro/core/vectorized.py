@@ -35,6 +35,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import ref as KREF
+
 from . import isa, jit as J, maps as M
 from .isa import BPF_JMP, BPF_JMP32, OP_MASK
 from .verifier import CallAnn, VerifiedProgram
@@ -45,32 +47,6 @@ _PURE = {"ktime_get_ns", "get_smp_processor_id", "get_current_pid_tgid",
          "log2"}
 _EFFECT = {"map_fetch_add", "percpu_fetch_add", "hist_add", "ringbuf_output",
            "override_return", "trace_printk"}
-
-
-def _ringbuf_emit_batch_fallback(data, head, rows, valid):
-    """Self-contained lax.scan twin of kernels.ref.ringbuf_emit_batch —
-    the EXPLICIT fallback when the optional kernels package is absent
-    (pinned by tests/test_kernels_fallback.py)."""
-    cap = data.shape[0]
-
-    def one(carry, ev):
-        d, h = carry
-        row, ok = ev
-        slot = (h[0] % cap).astype(jnp.int32)
-        d = d.at[slot].set(jnp.where(ok, row, d[slot]))
-        h = h.at[0].add(jnp.where(ok, jnp.int64(1), jnp.int64(0)))
-        return (d, h), jnp.int64(0)
-
-    (d, h), _ = jax.lax.scan(one, (data, head), (rows, valid))
-    return d, h
-
-
-def _ringbuf_emit_batch(data, head, rows, valid):
-    try:
-        from repro.kernels import ref as KREF
-    except ImportError:
-        return _ringbuf_emit_batch_fallback(data, head, rows, valid)
-    return KREF.ringbuf_emit_batch(data, head, rows, valid)
 
 
 def _r0_dead_after(vprog: VerifiedProgram, call_pc: int) -> bool:
@@ -240,7 +216,7 @@ def _apply_site(vp, name, statics, rec, maps_state, aux):
         sp = vp.map_specs[fd]
         st = maps_state[sp.name]
         head0 = st["head"][0]
-        d, h = _ringbuf_emit_batch(st["data"], st["head"], rec[1], ok)
+        d, h = KREF.ringbuf_emit_batch(st["data"], st["head"], rec[1], ok)
         # dropped accounting, batch form: the i-th valid record lands at
         # monotonic position head0 + rank(i); it laps (overwrites an unread
         # record) when that position >= capacity.

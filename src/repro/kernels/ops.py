@@ -6,6 +6,10 @@ impl:
   'pallas_interpret'  Pallas kernel body interpreted on CPU (tests)
 
 Default comes from REPRO_KERNEL_IMPL or the backend: TPU->pallas, else ref.
+On a TPU backend the default is always 'pallas': REPRO_KERNEL_IMPL naming
+'ref' or 'pallas_interpret' there raises rather than quietly running the
+jnp or interpreted path on the chip. An explicit `impl=` argument is
+honoured on every backend (references in tests and smoke checks).
 """
 from __future__ import annotations
 
@@ -22,10 +26,12 @@ def default_impl() -> str:
     global _DEFAULT
     if _DEFAULT is None:
         env = os.environ.get("REPRO_KERNEL_IMPL")
-        if env:
-            _DEFAULT = env
-        else:
-            _DEFAULT = ("pallas" if jax.default_backend() == "tpu" else "ref")
+        on_tpu = jax.default_backend() == "tpu"
+        if env and on_tpu and env != "pallas":
+            raise ValueError(
+                f"REPRO_KERNEL_IMPL={env!r} on a TPU backend: only 'pallas' "
+                f"runs on the chip (pass impl= explicitly for a reference)")
+        _DEFAULT = env or ("pallas" if on_tpu else "ref")
     return _DEFAULT
 
 

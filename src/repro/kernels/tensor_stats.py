@@ -5,7 +5,8 @@ attached probe costs ~1 read of the tensor (memory-roofline optimal) instead
 of 6 separate reductions. TPU adaptation of the paper's JIT'd probe body:
 the working set is tiled (BR, 1024) into VMEM; lane dim 1024 = 8×128 keeps
 the VPU fully packed; the grid walks rows sequentially and accumulates into
-(1,1) scalar output blocks (legal on TPU because the grid is sequential).
+(1,1) scalar outputs held in SMEM (legal on TPU because the grid is
+sequential; Mosaic cannot store scalars to VMEM).
 
 Layout: the wrapper flattens + zero-pads x to (R, 1024); a global-index mask
 inside the kernel excludes padding from every statistic.
@@ -17,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 LANES = 1024       # 8 sublanes * 128 lanes
 DEF_BLOCK_ROWS = 8
@@ -75,16 +77,19 @@ def tensor_stats_pallas(x, *, block_rows: int = DEF_BLOCK_ROWS,
     xf = xf.reshape(rows_pad, LANES)
 
     grid = (rows_pad // block_rows,)
-    scalar_spec = pl.BlockSpec((1, 1), lambda i: (0, 0))
+    scalar_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
     out_shape = [jax.ShapeDtypeStruct((1, 1), jnp.float32)] * 6
-    s, ss, mn, mx, nan, inf = pl.pallas_call(
-        functools.partial(_kernel, numel=numel, lanes=LANES),
-        grid=grid,
-        in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
-        out_specs=[scalar_spec] * 6,
-        out_shape=out_shape,
-        interpret=interpret,
-    )(xf)
+    # the package runs with x64 on, which makes the grid index and the
+    # index-map results i64; Mosaic only legalizes i32 there
+    with jax.enable_x64(False):
+        s, ss, mn, mx, nan, inf = pl.pallas_call(
+            functools.partial(_kernel, numel=numel, lanes=LANES),
+            grid=grid,
+            in_specs=[pl.BlockSpec((block_rows, LANES), lambda i: (i, 0))],
+            out_specs=[scalar_spec] * 6,
+            out_shape=out_shape,
+            interpret=interpret,
+        )(xf)
 
     s, ss = s[0, 0], ss[0, 0]
     mn, mx = mn[0, 0], mx[0, 0]

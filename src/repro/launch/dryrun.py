@@ -168,8 +168,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     out["analytic_state_bytes_global"] = _analytic_bytes(args, mesh)
 
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):          # newer jax: list of dicts
-        cost = cost[0] if cost else {}
     out["cost_xla_once"] = {          # XLA's own numbers (loop bodies x1)
         k: float(v) for k, v in cost.items()
         if isinstance(v, (int, float)) and

@@ -1,8 +1,12 @@
-"""End-to-end training driver (runs for real on CPU with smoke configs;
-lowers for the production mesh via dryrun.py).
+"""End-to-end training driver: one device, smoke or published widths
+(`--no-smoke`); the production mesh is only lowered, by dryrun.py.
 
-    PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b --smoke \
-        --steps 50 [--probes obj.json ...] [--shm /dev/shm/bpftime]
+    PYTHONPATH=src python -m repro.launch.train --arch qwen2-0.5b \
+        --steps 50 [--no-smoke --batch 4 --seq 1024] [--shm /dev/shm/bpftime]
+
+Probes reach a running job through the shm control plane (`--shm`, then
+`python -m repro.core.daemon <shm> --attach obj.json --target
+uprobe:block`).
 
 Integration points exercised here (the paper's workflow, §3.2):
   * probes attach/detach between steps WITHOUT restarting training —
@@ -146,7 +150,10 @@ def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--steps", type=int, default=20)
-    ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--smoke", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="reduced widths (default); --no-smoke runs the "
+                         "published config")
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
     ap.add_argument("--shm")
@@ -164,6 +171,8 @@ def main(argv=None):
                          "<shm>/cache when --shm is given)")
     args = ap.parse_args(argv)
 
+    from repro.jaxenv import use_compile_cache
+    use_compile_cache()
     from repro.core.runtime import BpftimeRuntime
     rt = BpftimeRuntime() if (args.shm or args.cache) else None
     state, hist = run_training(

@@ -128,3 +128,27 @@ def test_ops_dispatch():
     a = ops.tensor_stats(x, impl="ref")
     b = ops.tensor_stats(x, impl="pallas_interpret")
     np.testing.assert_allclose(float(a["mean"]), float(b["mean"]))
+
+
+@pytest.mark.parametrize("env", ["ref", "pallas_interpret"])
+def test_tpu_backend_refuses_a_non_pallas_default(monkeypatch, env):
+    """The chip never quietly runs the jnp or interpreted stats path."""
+    monkeypatch.setattr(ops, "_DEFAULT", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setenv("REPRO_KERNEL_IMPL", env)
+    with pytest.raises(ValueError, match="TPU backend"):
+        ops.default_impl()
+
+
+@pytest.mark.parametrize("env", [None, "pallas"])
+def test_tpu_backend_defaults_to_pallas(monkeypatch, env):
+    monkeypatch.setattr(ops, "_DEFAULT", None)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if env is None:
+        monkeypatch.delenv("REPRO_KERNEL_IMPL", raising=False)
+    else:
+        monkeypatch.setenv("REPRO_KERNEL_IMPL", env)
+    assert ops.default_impl() == "pallas"
+    # an explicit impl= still picks the reference (smoke checks use it)
+    st = ops.tensor_stats(jnp.arange(5.0, dtype=jnp.float32), impl="ref")
+    assert float(st["max"]) == 4.0
