@@ -1,0 +1,9 @@
+"""Share of the traced window in which no operation ran on the device, in
+%: 100 x (1 - union of device operation intervals / window), averaged
+over the chips used."""
+
+
+def read(ctx):
+    if ctx["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - ctx["busy_s"] / ctx["window_s"])
