@@ -1,11 +1,10 @@
-"""Benchmark harness — one section per paper table/figure.
+"""CPU benchmark harness of the probe pipeline, the map ops and the
+dry-run roofline.
 
     PYTHONPATH=src python -m benchmarks.run [--fast]
     PYTHONPATH=src python -m benchmarks.run --json BENCH_probe.json
 
 Sections:
-  table1   probe latency, kernel-mode vs bpftime-mode (paper Table 1)
-  fig3     VM/JIT micro-suite vs interpreter + native (paper Figure 3)
   maps     map-op throughput (ref vs Pallas-interpret)
   probe    probe-stage ns/event per exec mode (scan/vectorized/fused/
            interp — the live program-table lane) + live attach latency
@@ -99,27 +98,6 @@ def main(argv=None):
                   f"({wb['speedup']:.1f}x vs demoted row loop)")
         print(f"\nwrote {args.json}\nOK")
         return
-
-    section("table1_probe_latency (ns/event)")
-    from benchmarks import table1_probe_latency
-    print("name,ns_per_event,notes")
-    t1 = table1_probe_latency.run()
-    for name, ns, note in t1:
-        print(f"{name},{ns:.1f},{note}")
-    d = dict((n, v) for n, v, _ in t1)
-    user = d.get("uprobe_user") or d.get("embedding_runtime", 0)
-    if user:
-        print(f"# kernel/user uprobe ratio: "
-              f"{d['uprobe_kernel'] / user:.1f}x (paper: ~10x; user side "
-              f"uses {'in-step delta' if d.get('uprobe_user') else 'stage cost floor'})")
-
-    section("fig3_vm_perf (ns/exec)")
-    from benchmarks import fig3_vm_perf
-    print("name,tier,interp_ns,jit_ns,native_ns,jit_speedup")
-    for r in fig3_vm_perf.run():
-        print(f"{r['name']},{r['tier']},{r['interp_ns']:.0f},"
-              f"{r['jit_ns']:.0f},{r['native_ns']:.0f},"
-              f"{r['speedup']:.1f}x")
 
     section("map_ops (us/batch of 256 events)")
     from repro.kernels import ops

@@ -3,8 +3,8 @@
 Events cross the device->host boundary via io_callback (the int3 trap +
 double context switch of the paper), execute in the reference interpreter
 on host numpy maps, and the device waits. This is the baseline bpftime
-beats by 10x; benchmarks/table1_probe_latency.py measures our version of
-the same gap against the in-graph probe stage.
+beats by 10x; `tests/test_runtime.py` runs it over a collected tape into
+the host maps.
 """
 from __future__ import annotations
 

@@ -41,6 +41,9 @@ FX_ONE = 1 << FX_SHIFT
 _FX_MAX = (1 << 62) - 1
 
 I64 = jnp.int64
+# name scope of the collection's device work (stats, row building and
+# stacking): the `op_name` of every such XLA operation carries it
+COLLECT_SCOPE = "probe.collect"
 
 
 def to_fx(x):
@@ -150,22 +153,23 @@ class Collector:
         self.frames[-1].rows.append(rows)
 
     def emit_tensor_event(self, site_id: int, kind: int, tensor):
-        # stats only observe the tensor; without the stop the enclosing
-        # value_and_grad would try to differentiate the Pallas kernel
-        st = self._stats(jax.lax.stop_gradient(tensor))
-        row = jnp.stack([
-            jnp.asarray(site_id, I64),
-            jnp.asarray(kind, I64),
-            jnp.asarray(self.layer_ctx, I64),
-            jnp.asarray(0, I64),                       # step, filled later
-            jnp.asarray(tensor.size, I64),
-            to_fx(st["mean"]), to_fx(st["rms"]),
-            to_fx(st["min"]), to_fx(st["max"]), to_fx(st["absmax"]),
-            st["nan_cnt"].astype(I64), st["inf_cnt"].astype(I64),
-            jnp.asarray(0, I64), jnp.asarray(0, I64),
-            jnp.asarray(0, I64), jnp.asarray(0, I64),
-        ])
-        self.emit_row(row)
+        with jax.named_scope(COLLECT_SCOPE):
+            # stats only observe the tensor; without the stop the enclosing
+            # value_and_grad would try to differentiate the Pallas kernel
+            st = self._stats(jax.lax.stop_gradient(tensor))
+            row = jnp.stack([
+                jnp.asarray(site_id, I64),
+                jnp.asarray(kind, I64),
+                jnp.asarray(self.layer_ctx, I64),
+                jnp.asarray(0, I64),                       # step, filled later
+                jnp.asarray(tensor.size, I64),
+                to_fx(st["mean"]), to_fx(st["rms"]),
+                to_fx(st["min"]), to_fx(st["max"]), to_fx(st["absmax"]),
+                st["nan_cnt"].astype(I64), st["inf_cnt"].astype(I64),
+                jnp.asarray(0, I64), jnp.asarray(0, I64),
+                jnp.asarray(0, I64), jnp.asarray(0, I64),
+            ])
+            self.emit_row(row)
 
     def _stats(self, tensor):
         if self.stats_fn is not None:
@@ -178,7 +182,8 @@ class Collector:
             parts.append(r[None, :] if r.ndim == 1 else r)
         if not parts:
             return jnp.zeros((0, EVENT_WIDTH), I64)
-        return jnp.concatenate(parts, axis=0)
+        with jax.named_scope(COLLECT_SCOPE):
+            return jnp.concatenate(parts, axis=0)
 
     def take_all_rows(self):
         assert len(self.frames) == 1, "unbalanced frames"
@@ -268,5 +273,6 @@ def probed_scan(body, carry, xs, *, length=None, remat=False,
 
     f = jax.checkpoint(with_rows, policy=remat_policy) if remat else with_rows
     c_out, (ys, rows) = jax.lax.scan(f, carry, xs2, length=n)
-    col.emit_many(rows.reshape(-1, EVENT_WIDTH))
+    with jax.named_scope(COLLECT_SCOPE):
+        col.emit_many(rows.reshape(-1, EVENT_WIDTH))
     return c_out, ys

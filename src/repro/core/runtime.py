@@ -36,6 +36,7 @@ from dataclasses import dataclass, field
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import events as E, jit as J, loader, maps as M, syscalls as S, vm
 from .helpers import HELPERS
@@ -615,8 +616,9 @@ class BpftimeRuntime:
                                              mode)
         if table is not None:
             if self.live is not None and event_rows.shape[0] > 0:
-                map_states, aux = self.live.run(table, event_rows,
-                                                map_states, aux)
+                with jax.named_scope("probe.stage.table"):
+                    map_states, aux = self.live.run(table, event_rows,
+                                                    map_states, aux)
             map_states = {**map_states, "__live_table__": table}
         return map_states, aux
 
@@ -654,11 +656,13 @@ class BpftimeRuntime:
                         lane = vec if V.is_vector_safe(vprog) else rest
                         lane.append((sid, kind, vprog))
                 if vec:
-                    map_states, aux = V.run_fused_vector(
-                        vec, event_rows, map_states, aux)
+                    with jax.named_scope("probe.stage.vector"):
+                        map_states, aux = V.run_fused_vector(
+                            vec, event_rows, map_states, aux)
                 if rest:
-                    map_states, aux = J.run_fused_scan(
-                        rest, event_rows, map_states, aux)
+                    with jax.named_scope("probe.stage.combined_scan"):
+                        map_states, aux = J.run_fused_scan(
+                            rest, event_rows, map_states, aux)
                 return map_states, aux
             mode = "scan"
         for (sid, kind), pids in sorted(device_attach.items()):
@@ -669,11 +673,13 @@ class BpftimeRuntime:
                 if mode == "vectorized":
                     from . import vectorized as V
                     if V.is_vector_safe(vprog):
-                        map_states, aux = V.run_vectorized(
-                            vprog, event_rows, valid, map_states, aux)
+                        with jax.named_scope("probe.stage.vectorized"):
+                            map_states, aux = V.run_vectorized(
+                                vprog, event_rows, valid, map_states, aux)
                         continue
-                _, map_states, aux = J.run_over_events(
-                    vprog, event_rows, valid, map_states, aux)
+                with jax.named_scope("probe.stage.scan"):
+                    _, map_states, aux = J.run_over_events(
+                        vprog, event_rows, valid, map_states, aux)
         return map_states, aux
 
     # ---------------------------------------------------------------- shm
@@ -703,13 +709,21 @@ class BpftimeRuntime:
         self.publish_status()
         return self.shm
 
-    def publish(self, map_states) -> None:
+    def publish(self, map_states) -> int:
+        """Copy the device map states to the shm plane; returns the number
+        of leaves read from the device (0 without shm)."""
         if self.shm is None:
-            return
-        host_states = jax.tree.map(np.asarray, map_states)
-        self.syscalls.invoke(
-            "sys_shm_publish", [len(host_states)],
-            impl=lambda: self.shm.publish_device(host_states))
+            return 0
+        with TraceAnnotation("publish.fetch") as span:
+            host_states = jax.tree.map(np.asarray, map_states)
+            leaves = jax.tree.leaves(host_states)
+            span.set_metadata(leaves=len(leaves),
+                              bytes=sum(a.nbytes for a in leaves))
+        with TraceAnnotation("publish.write"):
+            self.syscalls.invoke(
+                "sys_shm_publish", [len(host_states)],
+                impl=lambda: self.shm.publish_device(host_states))
+        return len(leaves)
 
     def poll_control(self) -> list[dict]:
         """Pick up daemon attach/detach/load requests (between steps).

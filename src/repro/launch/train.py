@@ -23,6 +23,7 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 
 def run_training(arch: str, *, steps: int = 20, smoke: bool = True,
@@ -108,37 +109,69 @@ def run_training(arch: str, *, steps: int = 20, smoke: bool = True,
     history = []
     t0 = time.time()
     skips = 0          # consecutive vetoed/faulted batches: bounded spin
+    n_iter = 0
+    # host spans (jax.profiler annotations, recorded only while a trace is
+    # running): `train.step` holds one iteration, its children the host
+    # work between the step's end and the next dispatch. `d2h` counts the
+    # device->host reads of the iteration: the while test that admitted
+    # it, the hook's step, the metrics, the step after it and publish's
+    # leaves
     while int(state["step"]) < steps:
-        if runtime is not None:
-            runtime.poll_control()          # daemon injection point
-            # push any live-table change onto the running compiled step
-            # (no-op unless a live attach/detach happened since last sync)
-            state["maps"] = runtime.sync_live_table(state["maps"])
-            runtime.syscalls.invoke("sys_step_begin", [int(state["step"])],
-                                    impl=lambda: None)
-        batch_np = data.next()
-        if batch_np is None:                 # vetoed/faulted batch
-            skips += 1
-            if max_data_skips and skips >= max_data_skips:
-                raise RuntimeError(
-                    f"data pipeline yielded no batch {skips} times in a "
-                    f"row — a filter is vetoing every fetch")
-            continue
-        skips = 0
-        arm_promotion(batch_np)              # no-op after the first batch
-        step_fn = get_step_fn(batch_np)      # re-jits only on attach change
-        state, metrics = step_fn(state, batch_np)
-        history.append({k: float(np.asarray(v)) for k, v in metrics.items()})
-        s = int(state["step"])
-        if runtime is not None:
-            runtime.publish(state["maps"])
-            runtime.syscalls.invoke(
-                "sys_step_end", [s, int(1e6 * (time.time() - t0))],
-                impl=lambda: None)
-        if ckpt_dir and save_every and s % save_every == 0:
-            CK.save(ckpt_dir, s, state, runtime=runtime, blocking=True)
-        if on_step is not None:
-            on_step(s, state, metrics)
+        n_iter += 1
+        with StepTraceAnnotation("train.step",
+                                 step_num=n_iter) as step_span:
+            d2h = 1
+            if runtime is not None:
+                with TraceAnnotation("train.control") as span:
+                    applied = runtime.poll_control()   # daemon injection
+                    # push any live-table change onto the running compiled
+                    # step (no-op unless a live attach/detach happened
+                    # since last sync)
+                    state["maps"] = runtime.sync_live_table(state["maps"])
+                    span.set_metadata(applied=len(applied))
+                with TraceAnnotation("train.hook"):
+                    runtime.syscalls.invoke("sys_step_begin",
+                                            [int(state["step"])],
+                                            impl=lambda: None)
+                d2h += 1
+            with TraceAnnotation("train.data") as span:
+                batch_np = data.next()
+                span.set_metadata(vetoed=int(batch_np is None))
+            if batch_np is None:                 # vetoed/faulted batch
+                step_span.set_metadata(d2h=d2h)
+                skips += 1
+                if max_data_skips and skips >= max_data_skips:
+                    raise RuntimeError(
+                        f"data pipeline yielded no batch {skips} times in "
+                        f"a row — a filter is vetoing every fetch")
+                continue
+            skips = 0
+            arm_promotion(batch_np)              # no-op after the first
+            with TraceAnnotation("train.dispatch") as span:
+                n_built = len(jit_cache)
+                step_fn = get_step_fn(batch_np)  # re-jits on attach change
+                state, metrics = step_fn(state, batch_np)
+                span.set_metadata(built=len(jit_cache) - n_built)
+            with TraceAnnotation("train.wait"):
+                history.append({k: float(np.asarray(v))
+                                for k, v in metrics.items()})
+            s = int(state["step"])
+            d2h += len(metrics) + 1
+            if runtime is not None:
+                with TraceAnnotation("train.publish"):
+                    d2h += runtime.publish(state["maps"])
+                with TraceAnnotation("train.hook"):
+                    runtime.syscalls.invoke(
+                        "sys_step_end", [s, int(1e6 * (time.time() - t0))],
+                        impl=lambda: None)
+            step_span.set_metadata(d2h=d2h)
+            if ckpt_dir and save_every and s % save_every == 0:
+                with TraceAnnotation("train.ckpt"):
+                    CK.save(ckpt_dir, s, state, runtime=runtime,
+                            blocking=True)
+            if on_step is not None:
+                with TraceAnnotation("train.on_step"):
+                    on_step(s, state, metrics)
         if log_every and s % log_every == 0:
             print(f"step {s}: loss={history[-1]['loss']:.4f} "
                   f"gnorm={history[-1]['grad_norm']:.3f} "

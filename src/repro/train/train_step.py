@@ -88,7 +88,8 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, runtime=None,
                 nmb = batch["tokens"].shape[0]
                 grads = jax.tree.map(lambda a: a / nmb, acc)
                 loss = losses.mean()
-                rows = rows_stack.reshape(-1, E.EVENT_WIDTH)
+                with jax.named_scope(E.COLLECT_SCOPE):
+                    rows = rows_stack.reshape(-1, E.EVENT_WIDTH)
             else:
                 (loss, (metrics, rows)), grads = grad_fn(params, batch)
 
@@ -102,7 +103,8 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, runtime=None,
                     E.probe_site("grad.norm", gnorm.reshape(1))
                     E.probe_site("optimizer.update", loss.reshape(1))
                     rows2 = col.stacked_rows(fr)
-                rows = jnp.concatenate([rows, rows2], axis=0)
+                with jax.named_scope(E.COLLECT_SCOPE):
+                    rows = jnp.concatenate([rows, rows2], axis=0)
 
         lr = warmup_cosine(state["step"], lr=tcfg.lr, warmup=tcfg.warmup,
                            total=tcfg.total_steps)
@@ -114,7 +116,8 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, runtime=None,
         maps = state["maps"]
         aux = J.make_aux(time_ns=state["step"].astype(jnp.int64))
         if runtime is not None and rows.shape[0] > 0:
-            rows = rows.at[:, 3].set(state["step"].astype(jnp.int64))
+            with jax.named_scope(E.COLLECT_SCOPE):
+                rows = rows.at[:, 3].set(state["step"].astype(jnp.int64))
             maps, aux = runtime.probe_stage(rows, maps, aux,
                                             mode=probe_mode)
             # filter semantics: an override vetoes this step's update
