@@ -156,6 +156,9 @@ class BpftimeRuntime:
         self.syscalls = S.SyscallTable(self.host_maps, self.map_specs,
                                        pid=pid)
         self.shm = None
+        # {id: leaf} of the map leaves whose host copies `start_publish`
+        # started, held until the next `publish` reads them
+        self._in_flight: dict[int, object] = {}
         self._req_cursor = 0
         self._objects: dict[str, str] = {}   # name -> serialized object
         # 'fused' (default): single-pass multi-program dispatch;
@@ -709,21 +712,39 @@ class BpftimeRuntime:
         self.publish_status()
         return self.shm
 
+    def start_publish(self, map_states) -> list:
+        """Start the host copies of the map leaves the next `publish` will
+        read, and return those leaves ([] without shm: nothing is
+        published). Call it right after the step that made them is
+        dispatched, so the copies run behind the step."""
+        if self.shm is None:
+            return []
+        leaves = jax.tree.leaves(map_states)
+        for leaf in leaves:
+            leaf.copy_to_host_async()
+        self._in_flight = {id(leaf): leaf for leaf in leaves}
+        return leaves
+
     def publish(self, map_states) -> int:
-        """Copy the device map states to the shm plane; returns the number
-        of leaves read from the device (0 without shm)."""
+        """Copy the device map states to the shm plane in one batched read;
+        returns the blocking device->host reads that took: 0 where
+        `start_publish` already started every leaf's copy (or without
+        shm), else 1."""
         if self.shm is None:
             return 0
         with TraceAnnotation("publish.fetch") as span:
-            host_states = jax.tree.map(np.asarray, map_states)
+            in_flight, self._in_flight = self._in_flight, {}
+            prefetched = sum(in_flight.get(id(leaf)) is leaf
+                             for leaf in jax.tree.leaves(map_states))
+            host_states = jax.device_get(map_states)
             leaves = jax.tree.leaves(host_states)
-            span.set_metadata(leaves=len(leaves),
+            span.set_metadata(leaves=len(leaves), prefetched=prefetched,
                               bytes=sum(a.nbytes for a in leaves))
         with TraceAnnotation("publish.write"):
             self.syscalls.invoke(
                 "sys_shm_publish", [len(host_states)],
                 impl=lambda: self.shm.publish_device(host_states))
-        return len(leaves)
+        return int(prefetched < len(leaves))
 
     def poll_control(self) -> list[dict]:
         """Pick up daemon attach/detach/load requests (between steps).

@@ -22,7 +22,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 
@@ -110,17 +109,19 @@ def run_training(arch: str, *, steps: int = 20, smoke: bool = True,
     t0 = time.time()
     skips = 0          # consecutive vetoed/faulted batches: bounded spin
     n_iter = 0
+    s = int(state["step"])
     # host spans (jax.profiler annotations, recorded only while a trace is
     # running): `train.step` holds one iteration, its children the host
     # work between the step's end and the next dispatch. `d2h` counts the
-    # device->host reads of the iteration: the while test that admitted
-    # it, the hook's step, the metrics, the step after it and publish's
-    # leaves
-    while int(state["step"]) < steps:
+    # times the iteration blocks on a device->host read: the step's
+    # metrics, its step counter and the map leaves publish will read are
+    # copied in one batch started at dispatch and awaited once, in
+    # `train.wait`; publish adds one read only for leaves that batch
+    # did not start
+    while s < steps:
         n_iter += 1
         with StepTraceAnnotation("train.step",
                                  step_num=n_iter) as step_span:
-            d2h = 1
             if runtime is not None:
                 with TraceAnnotation("train.control") as span:
                     applied = runtime.poll_control()   # daemon injection
@@ -130,15 +131,13 @@ def run_training(arch: str, *, steps: int = 20, smoke: bool = True,
                     state["maps"] = runtime.sync_live_table(state["maps"])
                     span.set_metadata(applied=len(applied))
                 with TraceAnnotation("train.hook"):
-                    runtime.syscalls.invoke("sys_step_begin",
-                                            [int(state["step"])],
+                    runtime.syscalls.invoke("sys_step_begin", [s],
                                             impl=lambda: None)
-                d2h += 1
             with TraceAnnotation("train.data") as span:
                 batch_np = data.next()
                 span.set_metadata(vetoed=int(batch_np is None))
             if batch_np is None:                 # vetoed/faulted batch
-                step_span.set_metadata(d2h=d2h)
+                step_span.set_metadata(d2h=0)
                 skips += 1
                 if max_data_skips and skips >= max_data_skips:
                     raise RuntimeError(
@@ -151,12 +150,19 @@ def run_training(arch: str, *, steps: int = 20, smoke: bool = True,
                 n_built = len(jit_cache)
                 step_fn = get_step_fn(batch_np)  # re-jits on attach change
                 state, metrics = step_fn(state, batch_np)
+                reads = (metrics, state["step"],
+                         runtime.start_publish(state["maps"])
+                         if runtime is not None else [])
+                for leaf in jax.tree.leaves(reads[:2]):
+                    leaf.copy_to_host_async()
                 span.set_metadata(built=len(jit_cache) - n_built)
-            with TraceAnnotation("train.wait"):
-                history.append({k: float(np.asarray(v))
-                                for k, v in metrics.items()})
-            s = int(state["step"])
-            d2h += len(metrics) + 1
+            with TraceAnnotation("train.wait") as span:
+                host_metrics, host_step, _ = jax.device_get(reads)
+                history.append({k: float(v)
+                                for k, v in host_metrics.items()})
+                s = int(host_step)
+                span.set_metadata(arrays=len(jax.tree.leaves(reads)))
+            d2h = 1
             if runtime is not None:
                 with TraceAnnotation("train.publish"):
                     d2h += runtime.publish(state["maps"])
