@@ -11,12 +11,17 @@ given seconds and ends when the step hook raises `WindowClosed`; the
 hook keeps the last state it was given.
 
 Afterwards the device's peak memory is read, the program's state is
-freed, and the plain reference (`reference.py`) runs the same three
-steps so that `check.compare` can decide `correct`.
+freed, and the plain reference runs the same three steps so that
+`check.compare` can decide `correct`.
+
+What belongs to the architecture (the weights' tree, the reference's
+model, FLOPs and probed bytes) comes from the module the configuration
+names, resolved by `arch_module` before anything compiles (`arch/`).
 """
 from __future__ import annotations
 
 import contextlib
+import functools
 import gc
 import importlib.util
 import json
@@ -32,10 +37,15 @@ import flops
 import reference as R
 import traffic as T
 import xplane
-from weights import change_norms, make_batch, make_params
+from weights import make_batch
 
 BENCH_DIR = Path(__file__).resolve().parent
 ROOT = BENCH_DIR.parents[1]
+ARCH_DIR = BENCH_DIR / "arch"
+# the contract of an architecture module (arch/__init__.py)
+ARCH_FUNCTIONS = ("check_config", "make_params", "change_norms",
+                  "train_steps", "layer_sites", "flops_per_token",
+                  "site_bytes")
 WARMUP_STEPS = 5
 CHECK_STEPS = 3
 TRACE_STEPS = 10
@@ -84,6 +94,31 @@ def metric_reader(name: str):
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod.read
+
+
+@functools.lru_cache(maxsize=None)
+def _load_arch(path: Path):
+    spec = importlib.util.spec_from_file_location(f"arch_{path.stem}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def arch_module(cfg: dict):
+    """The architecture module `arch/<cfg["arch"]>.py`, with every function
+    of the contract, that computes the configuration's model."""
+    name = cfg.get("arch")
+    path = ARCH_DIR / f"{name}.py"
+    if not isinstance(name, str) or not path.is_file():
+        raise HarnessError(f"the configuration names no architecture module "
+                           f"in {ARCH_DIR}: \"arch\" is {name!r}")
+    mod = _load_arch(path)
+    missing = [f for f in ARCH_FUNCTIONS if not callable(getattr(mod, f, None))]
+    if missing:
+        raise HarnessError(f"architecture module {path} lacks "
+                           f"{', '.join(missing)}")
+    mod.check_config(cfg["model"])
+    return mod
 
 
 class _CompileCounter:
@@ -146,11 +181,16 @@ def run_cell(cfg: dict, traffic: dict, lim: dict, *, cell: str, seed: int,
     from repro.optim import make_optimizer
     from repro.train import train_step as TS
 
+    arch = arch_module(cfg)
     model, train = cfg["model"], cfg["train"]
-    arch = f"onchip:{cell}"
-    registry.ARCHS[arch] = mcfg = ModelConfig(name=arch, **model)
-    param_dtype = "bfloat16" if control else train["param_dtype"]
     B, S = traffic["batch"], traffic["seq_len"]
+    # the work a step does, counted before anything compiles
+    work = {"flops_per_token": arch.flops_per_token(model, S),
+            "stats_bytes_per_step": flops.stats_bytes_per_step(arch, model,
+                                                               traffic)}
+    arch_id = f"onchip:{cell}"
+    registry.ARCHS[arch_id] = mcfg = ModelConfig(name=arch_id, **model)
+    param_dtype = "bfloat16" if control else train["param_dtype"]
     calls = {"init": 0, "data": 0, "fetched": []}
     spans: list = []
     compiles = _CompileCounter.get()
@@ -165,7 +205,7 @@ def run_cell(cfg: dict, traffic: dict, lim: dict, *, cell: str, seed: int,
             raise HarnessError(f"run_training built {cfg_} / {stated}; the "
                                f"cell states {mcfg} / {want}")
         shape = jax.eval_shape(lambda: orig_init(key, cfg_, tcfg, runtime))
-        params = make_params(seed, model, param_dtype)
+        params = arch.make_params(seed, model, param_dtype)
         opt_init, _ = make_optimizer(tcfg.optimizer)
         state = {"params": params, "opt": jax.jit(opt_init)(params),
                  "step": jnp.zeros((), jnp.int32),
@@ -222,8 +262,8 @@ def run_cell(cfg: dict, traffic: dict, lim: dict, *, cell: str, seed: int,
             rec["grad1"] = {k: v / (1 - b1) for k, v in
                             R.leaf_norms(state["opt"]["m"]).items()}
         if step == CHECK_STEPS:
-            rec["change"] = R.named(change_norms(state["params"], seed,
-                                                 model, param_dtype))
+            rec["change"] = R.named(arch.change_norms(
+                state["params"], seed, model, param_dtype))
             rec["maps_check"] = _host_maps(state["maps"])
         if step < WARMUP_STEPS:
             return
@@ -265,7 +305,7 @@ def run_cell(cfg: dict, traffic: dict, lim: dict, *, cell: str, seed: int,
         with _patched(TS, "init_train_state", seeded_init), \
                 _patched(pipeline, "SyntheticDataset", SeededDataset):
             try:
-                run_training(arch, steps=train["total_steps"], smoke=False,
+                run_training(arch_id, steps=train["total_steps"], smoke=False,
                              runtime=rt, shm_dir=shm_dir,
                              probe_mode=traffic["probe_mode"], seq_len=S,
                              batch=B, microbatch=train["microbatch"],
@@ -314,13 +354,23 @@ def run_cell(cfg: dict, traffic: dict, lim: dict, *, cell: str, seed: int,
             (d.memory_stats() or {}).get("bytes_in_use", 0) for d in devices)
 
         if trace:
-            out.update(_reduce_trace(trace_dir, rec, spans, cfg, traffic,
-                                     per_layer, steps=rec["trace_steps"],
+            out.update(_reduce_trace(trace_dir, rec, spans, model, traffic,
+                                     work, per_layer,
+                                     steps=rec["trace_steps"],
                                      chips=len(devices)))
 
         batches = [make_batch(seed, s, B, S, model["vocab_size"])
                    for s in range(CHECK_STEPS)]
-        ref = R.train_steps(seed, model, train, batches)
+        ref = arch.train_steps(seed, model, train, batches)
+        # a reference that leaves out a site its module declares is the
+        # module's fault, and is named so, not read as the program's
+        stated = set(ref["sites"][0])
+        declared = set(arch.layer_sites(model)) | {
+            (s, R.POINT, 0) for s in R.SCALAR_SITES}
+        if stated != declared:
+            raise HarnessError(f"the reference states the sites "
+                               f"{sorted(stated ^ declared)} that its "
+                               f"layer_sites does not, or the reverse")
         from repro.core import events as E
         prog = {"loss": rec["loss"][:CHECK_STEPS],
                 "vetoed": rec["vetoed"][:CHECK_STEPS],
@@ -367,7 +417,7 @@ def _host_maps(maps: dict) -> dict:
             if not k.startswith("__")}
 
 
-def _reduce_trace(trace_dir, rec, spans, cfg, traffic, per_layer, *,
+def _reduce_trace(trace_dir, rec, spans, model, traffic, work, per_layer, *,
                   steps, chips) -> dict:
     """Device busy time, the breakdown and the per-layer metrics of the
     traced steps."""
@@ -387,13 +437,11 @@ def _reduce_trace(trace_dir, rec, spans, cfg, traffic, per_layer, *,
                   if xplane.overlap(o[1], o[2], lo, hi) > 0]
     host = [s for s in xplane.host_spans(profile) if s[0] != "bench.window"]
     window_s = (hi - lo) / 1e9
-    model = cfg["model"]
     ctx = {
         "model": model, "traffic": traffic, "chips": chips, "steps": steps,
         "window_s": window_s, "busy_s": float(np.mean(busy)) / 1e9,
         "tokens_per_step": traffic["batch"] * traffic["seq_len"],
-        "flops_per_token": flops.flops_per_token(model, traffic["seq_len"]),
-        "stats_bytes_per_step": flops.stats_bytes_per_step(model, traffic),
+        **work,
         "peaks": peaks(jax.devices()[0].device_kind),
         "ops": window_ops, "host_spans": host,
         "spans": [s for s in spans if s[1] >= rec["ends"][0]],

@@ -1,18 +1,19 @@
-"""Plain reference of the probed train step, in float32.
+"""Plain reference of the probed train step, in float32: what every
+architecture shares.
 
-A decoder-only transformer as the configuration file states it (RMSNorm,
-rotary embeddings on the whole head, grouped-query attention, SwiGLU,
-tied embeddings), its cross-entropy loss, gradients, clipping by the
-global norm and AdamW, written out in `jax.numpy` with every matrix
-product at `Precision.HIGHEST`. It imports nothing of the program.
+The architecture module (`arch/`, named by the configuration) states
+the model: its weights, its row loss and the probe-site statistics of
+each row. Here is the rest, written out in `jax.numpy`: the train loop
+over the batch one row at a time, clipping by the global norm and AdamW,
+the whole-batch statistics at each probe site from the rows' partials,
+and what the probe programs of a traffic mix compute from them: the
+maps that the programs' declared `reference` effects build. A
+reference computes every matrix product at `HI`, `Precision.HIGHEST`,
+and imports nothing of the program.
 
-It also states what the probe programs of a traffic mix compute: each
-probe site's statistics over the whole tensor, and the maps that the
-programs' declared `reference` effects build from them.
-
-Memory: one row of the batch at a time, with the layers under
-`jax.checkpoint`, so that a full-width step fits beside the optimizer
-state on one chip.
+Memory: one row of the batch at a time, with the gradient sum, the
+parameters and AdamW's moments updated in place, so that a full-width
+step fits beside the optimizer state on one chip.
 """
 from __future__ import annotations
 
@@ -23,13 +24,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from weights import change_norms, head_dim, make_params
-
 F32 = jnp.float32
 HI = jax.lax.Precision.HIGHEST
 ENTRY, EXIT, POINT = 0, 1, 2        # uprobe, uretprobe, probe
-LAYER_SITES = (("block", ENTRY), ("attn.out", POINT), ("ffn.out", POINT),
-               ("block", EXIT))
 SCALAR_SITES = ("loss", "grad.norm")
 
 
@@ -55,7 +52,7 @@ def leaf_norms(tree) -> dict:
     return named(_norms(tree))
 
 
-def _partials(x):
+def partials(x):
     """Mergeable statistics of one tensor: finite count, sum, sum of
     squares, min, max, NaN and Inf counts."""
     x = x.reshape(-1).astype(F32)
@@ -83,88 +80,13 @@ def merge_partials(parts: np.ndarray) -> dict:
             "nan_cnt": p[..., 5].sum(-1), "inf_cnt": p[..., 6].sum(-1)}
 
 
-def _rmsnorm(x, scale, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * scale
-
-
-def _rope(x, theta):
-    """x: [S, heads, hd]; rotate-half convention over the whole head."""
-    S, _, hd = x.shape
-    freqs = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=F32) / hd)
-    ang = jnp.arange(S, dtype=F32)[:, None] * freqs          # [S, hd/2]
-    cos, sin = jnp.cos(ang)[:, None, :], jnp.sin(ang)[:, None, :]
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def _layer(x, p, model):
-    S, D = x.shape
-    H, KH, hd = model["num_heads"], model["num_kv_heads"], head_dim(model)
-    eps = model["norm_eps"]
-    entry = _partials(x)
-    h = _rmsnorm(x, p["norm1"]["scale"], eps)
-    a = p["attn"]
-    q = jnp.matmul(h, a["wq"], precision=HI)
-    k = jnp.matmul(h, a["wk"], precision=HI)
-    v = jnp.matmul(h, a["wv"], precision=HI)
-    if model["qkv_bias"]:
-        q, k, v = q + a["bq"], k + a["bk"], v + a["bv"]
-    q = _rope(q.reshape(S, H, hd), model["rope_theta"])
-    k = _rope(k.reshape(S, KH, hd), model["rope_theta"])
-    v = v.reshape(S, KH, hd)
-    # query head i reads key/value head i // (H // KH)
-    k = jnp.repeat(k, H // KH, axis=1)
-    v = jnp.repeat(v, H // KH, axis=1)
-    s = jnp.einsum("qhd,khd->hqk", q, k, precision=HI) / math.sqrt(hd)
-    s = jnp.where(jnp.tril(jnp.ones((S, S), bool)), s, -jnp.inf)
-    o = jnp.einsum("hqk,khd->qhd", jax.nn.softmax(s, -1), v, precision=HI)
-    attn_out = jnp.matmul(o.reshape(S, H * hd), a["wo"], precision=HI)
-    x = x + attn_out
-    h = _rmsnorm(x, p["norm2"]["scale"], eps)
-    m = p["mlp"]
-    f = jax.nn.silu(jnp.matmul(h, m["wg"], precision=HI)) \
-        * jnp.matmul(h, m["wi"], precision=HI)
-    ffn_out = jnp.matmul(f, m["wo"], precision=HI)
-    x = x + ffn_out
-    stats = jnp.stack([entry, _partials(attn_out), _partials(ffn_out),
-                       _partials(x)])
-    return x, jax.lax.stop_gradient(stats)
-
-
-def _row_loss(params, tokens, labels, model):
-    """Summed next-token loss of one row, and its probe-site partials."""
-    V = model["vocab_size"]
-    emb = params["embed"]["embedding"]
-    x = emb[tokens]
-    embed_part = _partials(x)
-    blocks = params["stack"]["blocks"][0]
-    x, layer_parts = jax.lax.scan(
-        jax.checkpoint(lambda c, p: _layer(c, p, model)), x, blocks)
-    x = _rmsnorm(x, params["final_norm"]["scale"], model["norm_eps"])
-    logits = jnp.matmul(x, emb[:V].T, precision=HI)
-    logz = jax.nn.logsumexp(logits, -1)
-    picked = jnp.take_along_axis(logits, jnp.maximum(labels, 0)[:, None],
-                                 -1)[:, 0]
-    nll = jnp.where(labels >= 0, logz - picked, 0.0)
-    aux = {"embed": embed_part, "layers": layer_parts,
-           "logits": _partials(logits)}
-    return jnp.sum(nll), jax.lax.stop_gradient(aux)
-
-
 @functools.lru_cache(maxsize=None)
-def _fns(model_items: tuple, train_items: tuple):
-    model, train = dict(model_items), dict(train_items)
-    grad = jax.value_and_grad(lambda p, t, l: _row_loss(p, t, l, model),
-                              has_aux=True)
+def _update_fn(train_items: tuple):
+    train = dict(train_items)
 
-    # the gradient sum, the parameters and AdamW's moments are updated in
-    # place (donated), so a full-width step holds four parameter-sized
-    # trees and one row's temporaries
-    @functools.partial(jax.jit, donate_argnums=(1,))
-    def grad_row(params, gsum, tokens, labels):
-        (loss, aux), g = grad(params, tokens, labels)
-        return loss, aux, jax.tree.map(jnp.add, gsum, g)
-
+    # the parameters, the gradient sum and AdamW's moments are donated, so
+    # a full-width step holds four parameter-sized trees and one row's
+    # temporaries
     @functools.partial(jax.jit, donate_argnums=(0, 1, 3, 4))
     def update(params, gsum, count, m, v, step):
         g = jax.tree.map(lambda a: a / count, gsum)
@@ -191,30 +113,38 @@ def _fns(model_items: tuple, train_items: tuple):
             params, m, v)
         return params, m, v, g, gnorm
 
-    return grad_row, update
+    return update
 
 
-def train_steps(seed: int, model: dict, train: dict, batches: list) -> dict:
-    """Run the reference over `batches` from the seed's weights.
+_zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t))
+
+
+def run_steps(params, train: dict, batches: list, grad_row, sites: list,
+              change) -> dict:
+    """The reference's train loop over `batches` from `params`: the shared
+    half of an architecture's `train_steps`.
+
+    `grad_row(params, gsum, tokens, labels)` gives one row's summed loss,
+    its probe-site partials ([len(sites), 7], in the order of `sites`, the
+    architecture's `layer_sites`) and `gsum` plus the row's gradient
+    (`gsum` donated); `change(params)` the per-leaf norms of the change
+    from the start.
 
     Returns each step's loss and pre-clip global gradient norm, the
     per-leaf norms of the first clipped gradient, the per-leaf norms of
     the parameters' change over all the steps, and each step's probe-site
     statistics (`sites`: {(site, kind, layer): stats})."""
-    grad_row, update = _fns(tuple(sorted(model.items())),
-                            tuple(sorted(train.items())))
-    params = make_params(seed, model)
-    zeros = jax.jit(lambda t: jax.tree.map(jnp.zeros_like, t))
-    m, v = zeros(params), zeros(params)
+    update = _update_fn(tuple(sorted(train.items())))
+    m, v = _zeros(params), _zeros(params)
     out = {"loss": [], "grad_norm": [], "sites": []}
     for step, batch in enumerate(batches):
         tokens, labels = batch["tokens"], batch["labels"]
         count = float(np.sum(labels >= 0))
-        gsum, nll, parts = zeros(params), 0.0, []
+        gsum, nll, parts = _zeros(params), 0.0, []
         for r in range(tokens.shape[0]):
-            loss_r, aux, gsum = grad_row(params, gsum, tokens[r], labels[r])
+            loss_r, part, gsum = grad_row(params, gsum, tokens[r], labels[r])
             nll += float(loss_r)
-            parts.append(jax.tree.map(np.asarray, aux))
+            parts.append(part)
         params, m, v, g, gnorm = update(params, gsum, count, m, v,
                                         jnp.asarray(step))
         del gsum
@@ -224,30 +154,25 @@ def train_steps(seed: int, model: dict, train: dict, batches: list) -> dict:
         loss = nll / count
         out["loss"].append(loss)
         out["grad_norm"].append(float(gnorm))
-        out["sites"].append(_site_stats(parts, model, loss, float(gnorm)))
+        out["sites"].append(_site_stats(parts, sites, loss, float(gnorm)))
     del m, v
-    out["change"] = named(change_norms(params, seed, model))
+    out["change"] = named(change(params))
     return out
 
 
-def _site_stats(parts: list, model: dict, loss: float, gnorm: float) -> dict:
-    """Whole-batch statistics at each probe site of one step."""
-    sites = {("embed.out", POINT, 0): merge_partials(
-                 np.stack([p["embed"] for p in parts])),
-             ("logits", POINT, 0): merge_partials(
-                 np.stack([p["logits"] for p in parts]))}
-    layers = np.stack([p["layers"] for p in parts], axis=-2)   # [L,4,rows,7]
-    merged = merge_partials(layers)
-    for layer in range(model["num_layers"]):
-        for j, (site, kind) in enumerate(LAYER_SITES):
-            sites[(site, kind, layer)] = {k: v[layer, j]
-                                          for k, v in merged.items()}
+def _site_stats(parts: list, sites: list, loss: float, gnorm: float) -> dict:
+    """Whole-batch statistics at each probe site of one step: the rows'
+    partials merged site by site, for each (site, kind, layer) of
+    `sites`, and the scalar sites."""
+    merged = merge_partials(np.stack(parts, axis=-2))   # [sites, rows, 7]
+    out = {site: {k: v[i] for k, v in merged.items()}
+           for i, site in enumerate(sites)}
     for site, value in zip(SCALAR_SITES, (loss, gnorm)):
-        sites[(site, POINT, 0)] = {
+        out[(site, POINT, 0)] = {
             "mean": value, "rms": abs(value), "min": value, "max": value,
             "absmax": abs(value), "nan_cnt": float(math.isnan(value)),
             "inf_cnt": float(math.isinf(value))}
-    return sites
+    return out
 
 
 # ---------------------------------------------------------------- probes

@@ -10,7 +10,7 @@ import numpy as np
 import pytest
 
 import harness
-from weights import make_batch, make_params
+from weights import make_batch
 
 
 @pytest.mark.parametrize("mix", ["health", "idle"])
@@ -28,7 +28,8 @@ def test_mix_runs_through_run_training(smoke, mix):
 
 
 def test_the_seed_decides_weights_and_batches(tiny_model):
-    a, b, c = (make_params(s, tiny_model) for s in (5, 5, 2**31 + 5))
+    arch = harness.arch_module({"arch": "dense", "model": tiny_model})
+    a, b, c = (arch.make_params(s, tiny_model) for s in (5, 5, 2**31 + 5))
     leaves = [np.asarray(x) for x in
               (a["embed"]["embedding"], b["embed"]["embedding"],
                c["embed"]["embedding"])]

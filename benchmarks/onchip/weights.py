@@ -1,18 +1,19 @@
 """Weights and token batches made from the run's seed.
 
 Both the program under test and the plain reference take their weights
-from `make_params` and their batches from `make_batch`, so the two start
-from the same numbers while neither takes anything the other made.
+from the architecture module's `make_params` and their batches from
+`make_batch`, so the two start from the same numbers while neither takes
+anything the other made.
 
-The parameter tree has the layout the program's dense transformer keeps:
-layers stacked on a leading axis, the embedding padded to a multiple of
-256 rows (rows past the vocabulary are never looked up and are masked
-out of the logits).
+What is generic lives here: the seed as words and a key, jitting an
+architecture's tree maker so that the whole tree is made on the device
+in one call, the per-leaf change from the seed's tree, the program's
+vocabulary padding, and the batches. The tree itself is the
+architecture module's (`arch/`).
 """
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -22,11 +23,10 @@ F32 = jnp.float32
 
 
 def padded_vocab(model: dict) -> int:
+    """The vocabulary padded to a multiple of 256 rows, as the program
+    keeps its embedding (rows past the vocabulary are never looked up and
+    are masked out of the logits)."""
     return -(-model["vocab_size"] // 256) * 256
-
-
-def head_dim(model: dict) -> int:
-    return model.get("head_dim") or model["d_model"] // model["num_heads"]
 
 
 def seed_words(seed: int) -> np.ndarray:
@@ -36,57 +36,31 @@ def seed_words(seed: int) -> np.ndarray:
     return np.array([seed & 0xFFFFFFFF, seed >> 32], np.uint32)
 
 
-def _key(words):
+def seed_key(words):
+    """The PRNG key of the seed's words, inside a jitted tree maker."""
     key = jax.random.PRNGKey(0)
     return jax.random.fold_in(jax.random.fold_in(key, words[0]), words[1])
 
 
-def _params(words, *, model_items: tuple):
-    model = dict(model_items)
-    D, L = model["d_model"], model["num_layers"]
-    H, KH, hd, Fh = (model["num_heads"], model["num_kv_heads"],
-                     head_dim(model), model["d_ff"])
-    keys = iter(jax.random.split(_key(words), 16))
-
-    def normal(shape, scale):
-        return jax.random.normal(next(keys), shape, F32) * scale
-
-    attn = {"wq": normal((L, D, H * hd), 1 / math.sqrt(D)),
-            "wk": normal((L, D, KH * hd), 1 / math.sqrt(D)),
-            "wv": normal((L, D, KH * hd), 1 / math.sqrt(D)),
-            "wo": normal((L, H * hd, D), 1 / math.sqrt(H * hd))}
-    if model["qkv_bias"]:
-        attn.update(bq=normal((L, H * hd), 0.02),
-                    bk=normal((L, KH * hd), 0.02),
-                    bv=normal((L, KH * hd), 0.02))
-    block = {"norm1": {"scale": jnp.ones((L, D), F32)},
-             "attn": attn,
-             "norm2": {"scale": jnp.ones((L, D), F32)},
-             "mlp": {"wi": normal((L, D, Fh), 1 / math.sqrt(D)),
-                     "wg": normal((L, D, Fh), 1 / math.sqrt(D)),
-                     "wo": normal((L, Fh, D), 1 / math.sqrt(Fh))}}
-    return {"embed": {"embedding": normal((padded_vocab(model), D), 0.02)},
-            "stack": {"blocks": [block]},
-            "final_norm": {"scale": jnp.ones((D,), F32)}}
-
-
 @functools.lru_cache(maxsize=None)
-def _params_fn(model_items: tuple, dtype: str):
+def _params_fn(tree, model_items: tuple, dtype: str):
     def make(words):
-        p = _params(words, model_items=model_items)
+        p = tree(words, dict(model_items))
         return jax.tree.map(lambda a: a.astype(dtype), p)
     return jax.jit(make)
 
 
-def make_params(seed: int, model: dict, dtype: str = "float32"):
-    """The whole parameter tree on the default device, in one jitted call."""
-    return _params_fn(tuple(sorted(model.items())), dtype)(
+def seeded_tree(tree, seed: int, model: dict, dtype: str = "float32"):
+    """`tree(words, model)`, an architecture's float32 parameter tree from
+    the seed's words, in `dtype` on the default device, in one jitted
+    call."""
+    return _params_fn(tree, tuple(sorted(model.items())), dtype)(
         jnp.asarray(seed_words(seed)))
 
 
 @functools.lru_cache(maxsize=None)
-def _change_fn(model_items: tuple, dtype: str):
-    start = _params_fn(model_items, dtype)
+def _change_fn(tree, model_items: tuple, dtype: str):
+    start = _params_fn(tree, model_items, dtype)
 
     def norms(params, words):
         return jax.tree.map(
@@ -96,11 +70,12 @@ def _change_fn(model_items: tuple, dtype: str):
     return jax.jit(norms)
 
 
-def change_norms(params, seed: int, model: dict, dtype: str = "float32"):
-    """Per-leaf L2 norm of `params` less the seed's starting parameters, in
-    one jitted call that makes the start anew: no second parameter tree is
-    held beside `params` between calls."""
-    return _change_fn(tuple(sorted(model.items())), dtype)(
+def tree_change_norms(tree, params, seed: int, model: dict,
+                      dtype: str = "float32"):
+    """Per-leaf L2 norm of `params` less the seed's starting parameters
+    (`seeded_tree`), in one jitted call that makes the start anew: no
+    second parameter tree is held beside `params` between calls."""
+    return _change_fn(tree, tuple(sorted(model.items())), dtype)(
         params, jnp.asarray(seed_words(seed)))
 
 
